@@ -23,8 +23,9 @@ from .device_model import (CopyModel, DeviceProfile, LinearTimeModel, NO_COPY,
                            priority_order, tpu_group, with_pipeline,
                            TPU_PEAK_FLOPS, TPU_HBM_BW, TPU_ICI_BW,
                            TPU_VMEM_BYTES)
-from .predict import (Profiler, fit_linear, host_cpu_runner, load_profiles,
-                      relative_error, rmse, save_profiles, simulated_runner)
+from .predict import (Profiler, device_runner, fit_linear, load_profiles,
+                      measure_bandwidth, relative_error, rmse, save_profiles,
+                      simulated_runner)
 from .optimize import (GraphScheduleResult, MAKESPAN_OBJECTIVE, Objective,
                        OptimizeResult, SHARED_TEMPLATE_CACHE,
                        TemplatePlanCache, divisible_energy, graph_energy,
@@ -58,8 +59,8 @@ __all__ = [
     "RooflineTimeModel", "paper_mach1", "paper_mach2", "priority_order",
     "tpu_group", "with_pipeline", "TPU_PEAK_FLOPS", "TPU_HBM_BW",
     "TPU_ICI_BW", "TPU_VMEM_BYTES",
-    "Profiler", "fit_linear", "host_cpu_runner", "load_profiles",
-    "relative_error", "rmse", "save_profiles", "simulated_runner",
+    "Profiler", "device_runner", "fit_linear", "load_profiles",
+    "measure_bandwidth", "relative_error", "rmse", "save_profiles", "simulated_runner",
     "OptimizeResult", "solve_analytic", "solve_bisection",
     "solve_local_search",
     "DeviceAssignment", "GemmPlan", "SubProduct", "decompose_square",

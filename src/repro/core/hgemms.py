@@ -4,24 +4,37 @@ Splits an (m, n, k) GEMM's rows across heterogeneous devices per the POAS
 plan and executes the partitions through the overlapped co-execution runtime
 (``core.executor``): one thread per device, input/output copies serialized
 on the shared bus in the planned priority order, compute overlapping other
-devices' copies.  On this container every partition runs as a real jitted
-JAX matmul on the host CPU; per-device *times* come from the device models
-(the simulated testbed), while the *numerics* are real — so correctness
-(C == A@B), scheduling quality, and the executor's event ordering are all
-testable.
+devices' copies.
 
-On a TPU deployment the per-partition compute is the Pallas MXU matmul
-kernel (``repro.kernels.matmul``); the executor dispatches to it when the
-device kind is ``tpu-group`` and a TPU backend is present.
+``bind`` maps each POAS device profile to a ``jax.Device``.  A partition's
+``copy_in`` commits its A row-slice and B to that device
+(``jax.device_put``), ``compute`` runs there and ``copy_out`` reads its C
+rows back to the host; every stage ends in ``block_until_ready``, so the
+measured intervals are the transfers and the compute themselves, not their
+enqueue.  A device with no planned copy stage (the host CPU) computes in
+place.  A profile with a host link (an accelerator such as a TPU chip) runs
+the Pallas MXU kernel (``repro.kernels.matmul``); a profile without one runs
+an f32-accumulating XLA matmul.  Operands cross the link in the dtype the
+caller hands in — bf16 for a chip, the 2-byte dtype its ``CopyModel``
+prices — and C always accumulates and returns in f32.
+
+Without ``bind`` every partition runs the XLA matmul on the host CPU device:
+the simulated testbeds (``paper_mach1``/``mach2``) keep real numerics while
+their per-device *times* come from the device models.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 import time
-from typing import Sequence
+from typing import Callable, Mapping, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.ops import matmul as pallas_matmul
 from .adapt import GemmPlan
 from .bus import BusTopology
 from .device_model import DeviceProfile, with_pipeline
@@ -29,6 +42,21 @@ from .domain import PlanCache
 from .executor import DeviceTask, OverlappedExecutor
 from .framework import GemmWorkload, POASPlan, make_gemm_poas
 from .schedule import DynamicScheduler, Timeline, simulate_timeline
+
+
+@jax.jit
+def host_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """The host CPU's kernel: XLA matmul accumulating in f32.  Over bf16
+    operands it equals an f32 matmul of the same values (bf16 products are
+    exact in f32)."""
+    return jnp.matmul(a, b, preferred_element_type=jnp.float32)
+
+
+def mxu_matmul(a: jax.Array, b: jax.Array, *,
+               interpret: bool = False) -> jax.Array:
+    """An accelerator's kernel: the Pallas MXU matmul (``kernels.matmul``,
+    jitted at module level) with f32 accumulation and output."""
+    return pallas_matmul(a, b, out_dtype=jnp.float32, interpret=interpret)
 
 
 @dataclasses.dataclass
@@ -41,6 +69,8 @@ class ExecutionReport:
     standalone: dict[str, float]   # predicted time if each device ran alone
     per_device_seconds: dict[str, float]
     measured: Timeline | None = None   # executor's real per-stage intervals
+    # profile name -> the jax devices its computed C blocks lived on
+    placement: dict[str, set] = dataclasses.field(default_factory=dict)
 
     @property
     def speedups(self) -> dict[str, float]:
@@ -49,17 +79,30 @@ class ExecutionReport:
 
 
 class HGemms:
-    """Heterogeneous GEMM scheduler (paper §4)."""
+    """Heterogeneous GEMM scheduler (paper §4).
+
+    ``bind`` maps every profile name to the ``jax.Device`` its partitions
+    run on; ``interpret`` runs the accelerators' Pallas kernel in interpret
+    mode (CPU tests bind the CPU device in the chip's place)."""
 
     def __init__(self, devices: Sequence[DeviceProfile], *,
                  bus: str | BusTopology = "serialized",
                  dynamic: bool = False, cache: bool = True,
-                 pipeline_chunks: int | None = None):
+                 pipeline_chunks: int | None = None,
+                 bind: Mapping[str, jax.Device] | None = None,
+                 interpret: bool = False):
         self.devices = list(devices)
         if pipeline_chunks is not None:
             # chunked pipelined copies (DESIGN.md §4): the adapt phase maps
             # each copying device's chunk count to row-chunks of its A slice
             self.devices = with_pipeline(self.devices, pipeline_chunks)
+        if bind is not None:
+            names = {d.name for d in self.devices}
+            if set(bind) != names:
+                raise ValueError(f"bind must name exactly the profiles "
+                                 f"{sorted(names)}, got {sorted(bind)}")
+        self.bind = dict(bind) if bind is not None else None
+        self.interpret = interpret
         self.poas, self.dyn = make_gemm_poas(self.devices, bus=bus,
                                              dynamic=dynamic, cache=cache)
         self.bus = self.poas.domain.bus
@@ -76,104 +119,96 @@ class HGemms:
 
     # -- execution ---------------------------------------------------------
 
+    def _target(self, prof: DeviceProfile) -> tuple[jax.Device, Callable]:
+        """The device a profile's partitions run on, and their kernel."""
+        if self.bind is None:
+            return jax.devices("cpu")[0], host_matmul
+        if math.isinf(prof.copy.bandwidth_bytes_per_s):
+            return self.bind[prof.name], host_matmul
+        return self.bind[prof.name], functools.partial(
+            mxu_matmul, interpret=self.interpret)
+
     def _partition_tasks(self, a: np.ndarray, b: np.ndarray, c: np.ndarray,
-                         gplan: GemmPlan, planned: Timeline) -> list[DeviceTask]:
+                         gplan: GemmPlan, planned: Timeline,
+                         placement: dict[str, set]) -> list[DeviceTask]:
         """One ``DeviceTask`` per device with work; stages mirror the planned
         timeline (devices with no planned copy event compute in place).
         Devices with pipelined row chunks get per-chunk stage lists so the
         executor streams them — chunk 1's matmul really overlaps chunk 2's
-        copy, the overlap the chunked plan prices."""
-        import jax
-        import jax.numpy as jnp
-
-        @jax.jit
-        def mm(x, y):
-            return x @ y
-
+        copy, the overlap the chunked plan prices.  The shared B panel
+        rides input chunk 0, exactly how the engine prices it."""
         planned_kinds = {(e.device, e.kind) for e in planned.events}
         tasks: list[DeviceTask] = []
-        for dev, asg in zip(self.devices, gplan.assignments):
+        for prof, asg in zip(self.devices, gplan.assignments):
             if asg.m == 0:
                 continue
-            has_in = (dev.name, "copy_in") in planned_kinds
-            has_out = (dev.name, "copy_out") in planned_kinds
-            state: dict = {}
+            dev, kernel = self._target(prof)
+            has_in = (prof.name, "copy_in") in planned_kinds
+            has_out = (prof.name, "copy_out") in planned_kinds
             if has_in and len(asg.chunk_rows) > 1:
-                tasks.append(self._pipelined_task(
-                    mm, a, b, c, dev.name, asg, has_out, state))
-                continue
-            rows = slice(asg.row0, asg.row0 + asg.m)
+                chunks = [(r0, r0 + rr) for r0, rr in
+                          zip(asg.chunk_offsets(), asg.chunk_rows)]
+            else:
+                chunks = [(asg.row0, asg.row0 + asg.m)]
+            last = len(chunks) - 1
+            placed = placement[prof.name] = set()
+            state: dict = {}
 
-            def copy_in(state=state, rows=rows):
-                # host -> device: A row-slice + full B
-                state["a"] = jnp.asarray(a[rows])
-                state["b"] = jnp.asarray(b)
-
-            def compute(state=state, rows=rows):
-                if "a" not in state:      # no-copy device computes in place
-                    state["a"] = jnp.asarray(a[rows])
-                    state["b"] = jnp.asarray(b)
-                state["c"] = np.asarray(mm(state["a"], state["b"]))
-
-            def copy_out(state=state, rows=rows):
-                c[rows] = state["c"]
-
-            if not has_out:
-                # fold the C write into compute so the result still lands
-                def compute(state=state, rows=rows, inner=compute):
-                    inner()
-                    c[rows] = state["c"]
-            tasks.append(DeviceTask(
-                device=dev.name,
-                copy_in=copy_in if has_in else None,
-                compute=compute,
-                copy_out=copy_out if has_out else None))
-        return tasks
-
-    @staticmethod
-    def _pipelined_task(mm, a: np.ndarray, b: np.ndarray, c: np.ndarray,
-                        device: str, asg, has_out: bool,
-                        state: dict) -> DeviceTask:
-        """Per-chunk stage lists from the adapt phase's ``chunk_rows``: the
-        shared B panel rides input chunk 0 (exactly how the engine prices
-        it), chunk j's matmul consumes its own A slice, chunk j's C slice
-        lands in the output stage (or inside compute for no-copy-out)."""
-        import jax.numpy as jnp
-
-        in_chunks, comp_chunks, out_chunks = [], [], []
-        for j, (r0, rr) in enumerate(zip(asg.chunk_offsets(),
-                                         asg.chunk_rows)):
-            def copy_in(j=j, r0=r0, rr=rr, state=state):
+            def copy_in(j, r0, r1, dev=dev, state=state):
+                # host -> device: A row-slice (+ the full B with chunk 0)
                 if j == 0:
-                    state["b"] = jnp.asarray(b)
-                state["a", j] = jnp.asarray(a[r0:r0 + rr])
+                    state["b"] = jax.device_put(b, dev)
+                state["a", j] = jax.device_put(a[r0:r1], dev)
+                jax.block_until_ready((state["a", j], state["b"]))
 
-            def compute(j=j, r0=r0, rr=rr, state=state):
-                state["c", j] = np.asarray(mm(state["a", j], state["b"]))
-                if not has_out:
-                    c[r0:r0 + rr] = state["c", j]
+            def compute(j, r0, r1, kernel=kernel, placed=placed, state=state,
+                        copy_in=copy_in, has_in=has_in, has_out=has_out,
+                        last=last):
+                if not has_in:            # no-copy device computes in place
+                    copy_in(j, r0, r1)
+                out = kernel(state.pop(("a", j)), state["b"])
+                out.block_until_ready()
+                placed.update(out.devices())
+                if j == last:             # drop B: frees device memory early
+                    del state["b"]
+                if has_out:
+                    state["c", j] = out
+                else:
+                    c[r0:r1] = np.asarray(out)
 
-            def copy_out(j=j, r0=r0, rr=rr, state=state):
-                c[r0:r0 + rr] = state["c", j]
+            def copy_out(j, r0, r1, state=state):
+                # device -> host; dropping the array frees its device memory
+                c[r0:r1] = np.asarray(state.pop(("c", j)))
 
-            in_chunks.append(copy_in)
-            comp_chunks.append(compute)
-            out_chunks.append(copy_out)
-        return DeviceTask(
-            device=device, copy_in=None, compute=None, copy_out=None,
-            copy_in_chunks=in_chunks, compute_chunks=comp_chunks,
-            copy_out_chunks=out_chunks if has_out else None)
+            stages = [[functools.partial(fn, j, r0, r1)
+                       for j, (r0, r1) in enumerate(chunks)]
+                      for fn in (copy_in, compute, copy_out)]
+            if len(chunks) > 1:
+                tasks.append(DeviceTask(
+                    device=prof.name, copy_in=None, compute=None,
+                    copy_out=None, copy_in_chunks=stages[0],
+                    compute_chunks=stages[1],
+                    copy_out_chunks=stages[2] if has_out else None))
+            else:
+                tasks.append(DeviceTask(
+                    device=prof.name,
+                    copy_in=stages[0][0] if has_in else None,
+                    compute=stages[1][0],
+                    copy_out=stages[2][0] if has_out else None))
+        return tasks
 
     def execute(self, a: np.ndarray, b: np.ndarray, *,
                 noise: float = 0.0, seed: int = 0,
                 plan: POASPlan | None = None) -> tuple[np.ndarray, ExecutionReport]:
-        """Run the co-executed GEMM.  Returns (C, report).
+        """Run the co-executed GEMM.  Returns (C, report); C is f32 (or
+        wider, for wider operands).
 
-        Partitions run concurrently through ``OverlappedExecutor`` (real
-        numerics, real overlap, bus order from the plan); the per-device
-        *time* is taken from its model (optionally noised) so the simulated
-        testbed reproduces the paper's timing behaviour deterministically on
-        one CPU.
+        Partitions run concurrently through ``OverlappedExecutor`` on their
+        bound devices (real numerics, real overlap, bus order from the
+        plan); ``report.measured`` holds the executor's measured stage
+        intervals.  The report's simulated times come from the device models
+        (optionally noised), so an unbound simulated testbed reproduces the
+        paper's timing behaviour deterministically on one CPU.
         """
         m, k = a.shape
         k2, n = b.shape
@@ -182,9 +217,11 @@ class HGemms:
         gplan: GemmPlan = p.adapted
 
         rng = np.random.default_rng(seed)
-        c = np.zeros((m, n), dtype=np.result_type(a.dtype, b.dtype))
+        c = np.zeros((m, n), dtype=np.result_type(a.dtype, b.dtype,
+                                                  np.float32))
         planned = p.schedule.timeline
-        tasks = self._partition_tasks(a, b, c, gplan, planned)
+        placement: dict[str, set] = {}
+        tasks = self._partition_tasks(a, b, c, gplan, planned, placement)
 
         t0 = time.perf_counter()
         measured = OverlappedExecutor(self.devices, planned).run(tasks)
@@ -217,7 +254,7 @@ class HGemms:
                                    max(device_times.values(), default=0.0)),
             wall_seconds=wall, standalone=standalone,
             per_device_seconds=device_times,
-            measured=measured)
+            measured=measured, placement=placement)
         return c, rep
 
     # -- prediction accuracy experiment (paper §5.2) ------------------------

@@ -6,9 +6,10 @@ Builds per-device performance models.  Three sources, all producing the same
 1. ``fit_linear`` — least-squares linear regression of measured time over the
    op count (the paper's approach, §4.1.1).
 2. ``Profiler`` — the one-off profiling pass (paper §4.1.2): runs squared
-   matmuls of growing size, measures, and regresses.  On this container it
-   measures the real host CPU via jitted jnp matmuls; simulated device specs
-   reproduce the paper's testbed.
+   matmuls of growing size, measures, and regresses.  ``device_runner``
+   times a kernel on a real ``jax.Device`` (the host CPU, a TPU chip) and
+   ``measure_bandwidth`` times its host link; ``simulated_runner`` and
+   ``measure_bandwidth_simulated`` reproduce the paper's testbed.
 3. ``roofline_model`` — XLA-cost-analysis-driven predictor for TPU device
    groups (our hardware adaptation; see DESIGN.md §2).
 """
@@ -106,22 +107,25 @@ class Profiler:
                           [r.seconds for r in self.records])
 
 
-def host_cpu_runner(dtype=np.float32) -> Callable[[int], float]:
-    """Measure real jitted matmul wall time on the container CPU."""
+def device_runner(device, matmul: Callable, dtype) -> Callable[[int], float]:
+    """Measure ``matmul`` on a real ``jax.Device``: seeded square operands
+    of ``dtype`` are committed to ``device`` and warmed once (compile + first
+    run) per size; each call then times one run ending in
+    ``block_until_ready``."""
     import jax
-    import jax.numpy as jnp
 
-    @jax.jit
-    def mm(a, b):
-        return a @ b
+    held: dict[int, tuple] = {}
 
     def run(size: int) -> float:
-        key = np.random.default_rng(size)
-        a = jnp.asarray(key.standard_normal((size, size)), dtype=dtype)
-        b = jnp.asarray(key.standard_normal((size, size)), dtype=dtype)
-        mm(a, b).block_until_ready()  # warm the cache / compile
+        if size not in held:
+            held.clear()                  # keep one size's operands alive
+            rng = np.random.default_rng(size)
+            x, y = (rng.integers(-16, 16, (size, size), dtype=np.int8)
+                    .astype(dtype) for _ in range(2))
+            held[size] = jax.device_put((x, y), device)
+            matmul(*held[size]).block_until_ready()
         t0 = time.perf_counter()
-        mm(a, b).block_until_ready()
+        matmul(*held[size]).block_until_ready()
         return time.perf_counter() - t0
 
     return run
@@ -153,6 +157,25 @@ def measure_bandwidth_simulated(profile: DeviceProfile, *, nbytes: int = 1 << 28
     t = nbytes / profile.copy.bandwidth_bytes_per_s
     t *= 1.0 + noise * rng.standard_normal()
     return nbytes / max(t, 1e-12)
+
+
+def measure_bandwidth(device, *, nbytes: int = 256 << 20,
+                      repeats: int = 5) -> float:
+    """Paper's memory-bandwidth micro-benchmark on a real host link: the
+    median over ``repeats`` of a timed host->device ``device_put`` of
+    ``nbytes``, after one warm-up transfer."""
+    import jax
+
+    host = np.random.default_rng(0).integers(0, 256, nbytes, dtype=np.uint8)
+    jax.device_put(host, device).block_until_ready()
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        on_dev = jax.device_put(host, device)
+        on_dev.block_until_ready()
+        times.append(time.perf_counter() - t0)
+        on_dev.delete()
+    return nbytes / float(np.median(times))
 
 
 # ---------------------------------------------------------------------------

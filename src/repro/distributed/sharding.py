@@ -65,7 +65,11 @@ def _param_spec(path: tuple[str, ...], shape: tuple[int, ...],
     # ---- top level ----
     if not inside_layers:
         if name == "embed":
-            return spec("model", fsdp)
+            # vocab-parallel: the batch-sharded token gather partitions as a
+            # masked local gather + one all-reduce over "model"; a d@data
+            # shard would clash with the batch@data output and force XLA to
+            # rematerialize the whole table on every device
+            return spec("model", None)
         if name == "lm_head":
             return spec(fsdp, "model")
         if name == "adapter":

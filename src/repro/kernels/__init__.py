@@ -7,21 +7,10 @@
 
 Each has a pure-jnp oracle in ``ref.py``; kernels are validated in
 interpret mode on CPU (see tests/test_kernels_*.py) and run natively on TPU.
+Interpret mode is always the caller's explicit choice.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# Version-compat shim: jax >= 0.5 renamed ``TPUCompilerParams`` to
-# ``CompilerParams``; older releases (e.g. 0.4.37 on this container) only
-# ship the TPU-prefixed name.  Kernel modules import this package-local
-# alias (``from . import CompilerParams``) — jax's own namespace is left
-# untouched.  Defined before the kernel imports below so it is bound when
-# they load.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or _pltpu.TPUCompilerParams
-
 from .ops import flash_attention, matmul
 from .ssd_chunk import ssd_chunk_pallas
 from . import ref
 
-__all__ = ["CompilerParams", "flash_attention", "matmul",
-           "ssd_chunk_pallas", "ref"]
+__all__ = ["flash_attention", "matmul", "ssd_chunk_pallas", "ref"]

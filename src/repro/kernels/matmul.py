@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -67,7 +65,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

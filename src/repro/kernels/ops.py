@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites run everywhere:
-real MXU kernels on TPU, Python-interpreted (bit-accurate) on CPU.
+``interpret`` is the caller's choice and is never inferred: the chip path
+passes nothing (native Mosaic kernels), CPU tests pass ``interpret=True``
+(Python-interpreted, bit-accurate).
 """
 from __future__ import annotations
 
@@ -13,27 +14,20 @@ from .flash_attention import flash_attention_pallas
 from .matmul import matmul_pallas
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
-                                   "interpret"))
+                                   "out_dtype", "interpret"))
 def matmul(a, b, *, block_m: int = 256, block_n: int = 256,
-           block_k: int = 512, interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+           block_k: int = 512, out_dtype=None, interpret: bool = False):
     return matmul_pallas(a, b, block_m=block_m, block_n=block_n,
-                         block_k=block_k, interpret=interpret)
+                         block_k=block_k, out_dtype=out_dtype,
+                         interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool | None = None):
-    if interpret is None:
-        interpret = _default_interpret()
+                    interpret: bool = False):
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   block_q=block_q, block_k=block_k,
                                   interpret=interpret)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams
-
 F32 = jnp.float32
 
 
@@ -95,7 +93,7 @@ def ssd_chunk_pallas(xdt: jax.Array, B: jax.Array, C: jax.Array,
             jax.ShapeDtypeStruct((b, nc, nh, Q, hp), xdt.dtype),
             jax.ShapeDtypeStruct((b, nc, nh, ds, hp), F32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(xdt_t, b_t, c_t, cum_t)

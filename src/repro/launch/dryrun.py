@@ -33,7 +33,7 @@ from ..distributed.sharding import (batch_shardings, cache_shardings,  # noqa: E
 from ..models import Model  # noqa: E402
 from ..training.step import (default_optimizer, make_serve_step,  # noqa: E402
                              make_prefill_step, make_train_step)
-from .mesh import make_production_mesh  # noqa: E402
+from .mesh import make_debug_mesh, make_production_mesh  # noqa: E402
 from .specs import SHAPES, input_specs, param_specs, shape_applicable  # noqa: E402
 
 COLLECTIVE_RE = re.compile(
@@ -229,8 +229,7 @@ def main(argv=None) -> int:
             dims = tuple(int(x) for x in args.mesh_shape.split(","))
             axes = (("pod", "data", "model") if len(dims) == 3
                     else ("data", "model"))
-            mesh = jax.make_mesh(dims, axes,
-                                 devices=jax.devices()[:math.prod(dims)])
+            mesh = make_debug_mesh(dims, axes)
             if multi:
                 continue  # custom mesh: run once
         else:

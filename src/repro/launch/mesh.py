@@ -10,6 +10,15 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with Auto axes.  The model code places activations
+    with ``with_sharding_constraint`` and lets XLA propagate the rest, which
+    only Auto axes accept; ``jax.make_mesh`` defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,10 +31,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "run under launch/dryrun.py (it forces 512 host devices) or on "
             "real hardware")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Tiny mesh for unit tests (requires forced host device count)."""
     need = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _auto_mesh(shape, axes, jax.devices()[:need])

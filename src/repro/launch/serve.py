@@ -15,6 +15,7 @@ from ..configs import ARCH_IDS, get_config, get_tiny_config
 from ..core.device_model import DeviceProfile, LinearTimeModel, NO_COPY
 from ..models import Model
 from ..serving.engine import PoasDispatcher, Request, ServingEngine
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -27,6 +28,7 @@ def main(argv=None) -> int:
                     help="simulated replica groups for POAS dispatch")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_tiny_config(args.arch) if args.tiny else get_config(args.arch)
     if cfg.frontend != "none":

@@ -23,6 +23,7 @@ from ..distributed.elastic import FaultTolerantRunner, RunnerConfig
 from ..models import Model
 from ..training.optim import AdamW, cosine_schedule
 from ..training.step import make_train_step
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = Model(cfg)

@@ -30,16 +30,6 @@ from .config import ArchConfig
 from .layers import Params, _init, _dtype
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax >= 0.6 exposes ``jax.shard_map`` (check_vma); older releases ship
-    ``jax.experimental.shard_map.shard_map`` (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
 F32 = jnp.float32
 
 
@@ -157,10 +147,10 @@ def moe_block(p: Params, x: jax.Array, cfg: ArchConfig, mesh=None,
         wspec = P(model_axis, fsdp_axis, None)
         wospec = P(model_axis, None, fsdp_axis)
         xspec = P(batch_axes, None, None)
-        out = _shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None), wspec, wspec, wospec, xspec),
-            out_specs=xspec,
+            out_specs=xspec, check_vma=False,
         )(p["router"], p["w_in"], p["w_gate"], p["w_out"], x)
 
     if "shared" in p:

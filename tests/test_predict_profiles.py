@@ -99,3 +99,30 @@ def test_fit_linear_degenerate_model_safe_for_solvers():
     r2 = solve_bisection(devs, 1e9, n=100, k=100, bus="independent")
     assert sum(r2.ops) == pytest.approx(1e9, rel=1e-6)
     assert math.isfinite(r2.makespan)
+
+
+# ------------------------------------------------ real-device profiling -----
+
+def test_device_runner_fits_a_real_device():
+    """The profiling pass on a real jax.Device: warmed, block_until_ready
+    timings of the partition kernel that regress to a usable model."""
+    import jax
+    import numpy as np
+
+    from repro.core import Profiler, device_runner
+    from repro.core.hgemms import host_matmul
+
+    cpu = jax.devices("cpu")[0]
+    prof = Profiler(device_runner(cpu, host_matmul, np.float32), repeats=2)
+    records = prof.run([64, 128, 256])
+    assert all(r.seconds > 0 for r in records)
+    assert prof.fit().a > 0
+
+
+def test_measure_bandwidth_times_a_real_transfer():
+    import jax
+
+    from repro.core import measure_bandwidth
+
+    bw = measure_bandwidth(jax.devices("cpu")[0], nbytes=1 << 20, repeats=3)
+    assert math.isfinite(bw) and bw > 0
