@@ -1,0 +1,8 @@
+"""chip_store_s: per job, the seconds of the chip partitions' ``store``
+phases (the host copy of the read rows into C, inside copy_out) summed, the
+mean over the window's jobs."""
+from chipbench.phases import chip_names, mean_phase_s
+
+
+def read(run):
+    return mean_phase_s(run, chip_names(run), "copy_out", "store")

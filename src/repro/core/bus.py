@@ -79,6 +79,11 @@ class BusEvent:
     # Task-graph timelines attribute every event to a named task (None for
     # the divisible-workload engine, where a device runs exactly one unit).
     task: str | None = None
+    # Measured events only (the executor's stages): the parts the stage's
+    # callable timed, each (name, start, end) on the event's clock, and the
+    # seconds the stage waited for its link ticket before ``start``.
+    phases: tuple[tuple[str, float, float], ...] = ()
+    wait: float = 0.0
 
     @property
     def duration(self) -> float:

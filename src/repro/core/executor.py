@@ -21,18 +21,63 @@ Measured wall-clock intervals are recorded per stage as ``Timeline``s of
 ``BusEvent``s — per job *and* for the whole stream — so the same invariant
 checks (per-link serialization, priority order, compute-after-copy) apply
 to a real run, to a whole job stream across plan boundaries, and to the
-simulation.
+simulation.  Each event also carries the seconds its stage waited for a
+link ticket and the ``phase``s its callable timed.  Every stage, phase and
+ticket wait is a ``jax.profiler.TraceAnnotation`` too (``poas.<stage>``,
+``poas.<stage>.<phase>``, ``poas.bus_wait``), so a profiler trace puts the
+host's part of each device-idle gap on the device's clock.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
 import time
-from typing import Callable, Mapping, Sequence
+from typing import Callable, Iterator, Mapping, Sequence
+
+import jax
 
 from .bus import BusEvent, Timeline
 from .device_model import DeviceProfile
+
+# the stage this thread is running: (kind, clock origin, span arguments,
+# the phases recorded so far)
+_running = threading.local()
+
+
+@contextlib.contextmanager
+def _stage(kind: str, t0: float, phases: list, **args) -> Iterator[None]:
+    """Run one stage call under its profiler span ``poas.<kind>``, with
+    ``phases`` collecting the ``phase``s it opens on this thread."""
+    outer = getattr(_running, "stage", None)
+    _running.stage = (kind, t0, args, phases)
+    try:
+        with jax.profiler.TraceAnnotation(f"poas.{kind}", **args):
+            yield
+    finally:
+        _running.stage = outer
+
+
+@contextlib.contextmanager
+def phase(name: str, **args) -> Iterator[None]:
+    """Time one part of the stage running on this thread: the profiler span
+    ``poas.<stage>.<name>`` (the stage's arguments plus ``args``), and
+    ``(name, start, end)`` on the stream clock in the stage's
+    ``BusEvent.phases``.  Outside a stage, only the span ``poas.<name>``."""
+    running = getattr(_running, "stage", None)
+    if running is None:
+        with jax.profiler.TraceAnnotation(f"poas.{name}", **args):
+            yield
+        return
+    kind, t0, stage_args, phases = running
+    with jax.profiler.TraceAnnotation(f"poas.{kind}.{name}", **stage_args,
+                                      **args):
+        start = time.perf_counter() - t0
+        try:
+            yield
+        finally:
+            phases.append((name, start, time.perf_counter() - t0))
 
 
 @dataclasses.dataclass
@@ -322,8 +367,10 @@ class StreamCore:
 
     def _record(self, handle: JobHandle, device: str, kind: str, link: str | None,
                 start: float, end: float, chunk: int = 0,
-                task: str | None = None) -> None:
-        ev = BusEvent(device, kind, start, end, link, chunk, task)
+                task: str | None = None, phases: Sequence = (),
+                wait: float = 0.0) -> None:
+        ev = BusEvent(device, kind, start, end, link, chunk, task,
+                      tuple(phases), wait)
         with self._lock:
             self._events.append(ev)
         with handle._lock:
@@ -546,15 +593,21 @@ class StreamCore:
     # -- per-device stage groups -------------------------------------------
 
     def _acquire(self, jid: str, task: DeviceTask, kind: str,
-                 ticket_link: Mapping[tuple, str]) -> tuple[TicketBus, tuple]:
+                 ticket_link: Mapping[tuple, str]
+                 ) -> tuple[TicketBus, tuple, float]:
+        """Wait for the stage's link ticket, under the profiler span
+        ``poas.bus_wait``.  Returns (bus, ticket, seconds waited)."""
         base = task.ticket(kind)
         link = ticket_link.get(base)
         if link is None:
             raise ValueError(f"ticket {base} not in bus schedule")
         bus = self._bus(link)
         ticket = (jid,) + base
-        bus.acquire(ticket)
-        return bus, ticket
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("poas.bus_wait", job=jid,
+                                          device=task.device, chunk=0):
+            bus.acquire(ticket)
+        return bus, ticket, time.perf_counter() - t
 
     def _run_task(self, handle: JobHandle, jid: str, task: DeviceTask,
                   ticket_link: Mapping[tuple, str], inc: int = 0) -> None:
@@ -607,11 +660,16 @@ class StreamCore:
                     ticket_link: Mapping[tuple, str]) -> None:
         def stage(kind: str, fn: Callable[[], None], on_bus: bool) -> None:
             bus = ticket = None
+            wait = 0.0
             if on_bus:
-                bus, ticket = self._acquire(jid, task, kind, ticket_link)
+                bus, ticket, wait = self._acquire(jid, task, kind,
+                                                  ticket_link)
+            phases: list = []
             start = time.perf_counter() - self._t0
             try:
-                fn()
+                with _stage(kind, self._t0, phases, job=jid,
+                            device=task.device, chunk=0):
+                    fn()
             finally:
                 # stamp the end BEFORE releasing the bus: the next holder may
                 # start immediately, and measured bus events must not overlap
@@ -620,7 +678,7 @@ class StreamCore:
                     bus.release(ticket)
             self._record(handle, task.device, kind,
                          ticket_link.get(task.ticket(kind)), start, end,
-                         task=task.task)
+                         task=task.task, phases=phases, wait=wait)
 
         if task.copy_in is not None:
             stage("copy_in", task.copy_in, on_bus=True)
@@ -654,11 +712,14 @@ class StreamCore:
                         landed.acquire()
                         if aborted.is_set():
                             return
+                    phases: list = []
                     start = time.perf_counter() - t0
-                    fn()
+                    with _stage("compute", t0, phases, job=jid, device=dev,
+                                chunk=j):
+                        fn()
                     self._record(handle, dev, "compute", None, start,
                                  time.perf_counter() - t0, chunk=j,
-                                 task=task.task)
+                                 task=task.task, phases=phases)
                     computed.release()
             except BaseException as exc:
                 consumer_errs.append(exc)
@@ -671,16 +732,22 @@ class StreamCore:
 
         consumer = threading.Thread(target=consume, daemon=True)
         if in_chunks:
-            bus, ticket = self._acquire(jid, task, "copy_in", ticket_link)
+            bus, ticket, wait = self._acquire(jid, task, "copy_in",
+                                              ticket_link)
             consumer.start()
             try:
                 for j, fn in enumerate(in_chunks):
+                    phases = []
                     start = time.perf_counter() - t0
-                    fn()
+                    with _stage("copy_in", t0, phases, job=jid, device=dev,
+                                chunk=j):
+                        fn()
+                    # the chunks share one ticket: chunk 0 carries its wait
                     self._record(handle, dev, "copy_in",
                                  ticket_link.get(task.ticket("copy_in")),
                                  start, time.perf_counter() - t0, chunk=j,
-                                 task=task.task)
+                                 task=task.task, phases=phases,
+                                 wait=wait if j == 0 else 0.0)
                     landed.release()
             except BaseException:
                 # unblock the consumer before surfacing the error
@@ -692,18 +759,23 @@ class StreamCore:
         else:
             consumer.start()
         if out_chunks:
-            bus, ticket = self._acquire(jid, task, "copy_out", ticket_link)
+            bus, ticket, wait = self._acquire(jid, task, "copy_out",
+                                              ticket_link)
             try:
                 for j, fn in enumerate(out_chunks):
                     computed.acquire()   # chunk j's matmul is done
                     if consumer_errs or aborted.is_set():
                         break
+                    phases = []
                     start = time.perf_counter() - t0
-                    fn()
+                    with _stage("copy_out", t0, phases, job=jid, device=dev,
+                                chunk=j):
+                        fn()
                     self._record(handle, dev, "copy_out",
                                  ticket_link.get(task.ticket("copy_out")),
                                  start, time.perf_counter() - t0, chunk=j,
-                                 task=task.task)
+                                 task=task.task, phases=phases,
+                                 wait=wait if j == 0 else 0.0)
             finally:
                 bus.release(ticket)
         consumer.join()

@@ -10,7 +10,10 @@ plans in a ``PlanCache`` keyed on (workload geometry, device models).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Hashable, Sequence
+
+import jax
 
 from .adapt import GemmPlan, ops_to_mnk
 from .bus import BusTopology
@@ -65,7 +68,11 @@ class POAS:
         """Legacy construction from four loose callables (uncached)."""
         return cls(FunctionDomain(name, predict, optimize, adapt, schedule))
 
+    @functools.partial(jax.profiler.annotate_function, name="poas.plan")
     def plan(self, workload: Workload) -> POASPlan:
+        """The plan for ``workload``, under the profiler span ``poas.plan``
+        with a child per phase it solves (``.optimize``, ``.adapt``,
+        ``.schedule``); a cache hit solves none."""
         devices = list(self.domain.predict())
         key: Hashable | None = None
         if self.cache is not None:
@@ -75,9 +82,12 @@ class POAS:
                 # shallow copy carrying the *caller's* workload; the solved
                 # phases (optimize/adapted/schedule) are shared
                 return dataclasses.replace(hit, workload=workload)
-        opt = self.domain.optimize(devices, workload)
-        adapted = self.domain.adapt(devices, opt, workload)
-        sched = self.domain.schedule(devices, adapted, workload)
+        with jax.profiler.TraceAnnotation("poas.plan.optimize"):
+            opt = self.domain.optimize(devices, workload)
+        with jax.profiler.TraceAnnotation("poas.plan.adapt"):
+            adapted = self.domain.adapt(devices, opt, workload)
+        with jax.profiler.TraceAnnotation("poas.plan.schedule"):
+            sched = self.domain.schedule(devices, adapted, workload)
         plan = POASPlan(workload=workload, optimize=opt, adapted=adapted,
                         schedule=sched)
         if self.cache is not None and key is not None:

@@ -12,9 +12,12 @@ devices' copies.
 rows back to the host; every stage ends in ``block_until_ready``, so the
 measured intervals are the transfers and the compute themselves, not their
 enqueue.  A device with no planned copy stage (the host CPU) computes in
-place.  A profile with a host link (an accelerator such as a TPU chip) runs
-the Pallas MXU kernel (``repro.kernels.matmul``); a profile without one runs
-an f32-accumulating XLA matmul.  Operands cross the link in the dtype the
+place.  Inside the stages, ``phase``s split the work the stage interval
+holds: a copy_out's ``d2h`` read and its ``store`` into C; an in-place
+compute's operand ``put``, its ``kernel`` and its ``store``.  A profile
+with a host link (an accelerator such as a TPU chip) runs the Pallas MXU
+kernel (``repro.kernels.matmul``); a profile without one runs an
+f32-accumulating XLA matmul.  Operands cross the link in the dtype the
 caller hands in — bf16 for a chip, the 2-byte dtype its ``CopyModel``
 prices — and C always accumulates and returns in f32.
 
@@ -39,7 +42,7 @@ from .adapt import GemmPlan
 from .bus import BusTopology
 from .device_model import DeviceProfile, with_pipeline
 from .domain import PlanCache
-from .executor import DeviceTask, OverlappedExecutor
+from .executor import DeviceTask, OverlappedExecutor, phase
 from .framework import GemmWorkload, POASPlan, make_gemm_poas
 from .schedule import DynamicScheduler, Timeline, simulate_timeline
 
@@ -67,7 +70,6 @@ class ExecutionReport:
     simulated_makespan: float      # from device models (+noise if asked)
     wall_seconds: float            # actual host wall time of the partitions
     standalone: dict[str, float]   # predicted time if each device ran alone
-    per_device_seconds: dict[str, float]
     measured: Timeline | None = None   # executor's real per-stage intervals
     # profile name -> the jax devices its computed C blocks lived on
     placement: dict[str, set] = dataclasses.field(default_factory=dict)
@@ -165,20 +167,30 @@ class HGemms:
                         copy_in=copy_in, has_in=has_in, has_out=has_out,
                         last=last):
                 if not has_in:            # no-copy device computes in place
-                    copy_in(j, r0, r1)
-                out = kernel(state.pop(("a", j)), state["b"])
-                out.block_until_ready()
+                    with phase("put", bytes=b.nbytes
+                               + (r1 - r0) * a.shape[1] * a.itemsize):
+                        copy_in(j, r0, r1)
+                with phase("kernel"):
+                    out = kernel(state.pop(("a", j)), state["b"])
+                    out.block_until_ready()
                 placed.update(out.devices())
                 if j == last:             # drop B: frees device memory early
                     del state["b"]
                 if has_out:
                     state["c", j] = out
                 else:
-                    c[r0:r1] = np.asarray(out)
+                    with phase("store",
+                               bytes=(r1 - r0) * c.shape[1] * c.itemsize):
+                        c[r0:r1] = np.asarray(out)
 
             def copy_out(j, r0, r1, state=state):
                 # device -> host; dropping the array frees its device memory
-                c[r0:r1] = np.asarray(state.pop(("c", j)))
+                nbytes = (r1 - r0) * c.shape[1] * c.itemsize
+                with phase("d2h", bytes=nbytes):
+                    host = np.asarray(state.pop(("c", j)))
+                with phase("store", bytes=nbytes):
+                    c[r0:r1] = host
+                    del host
 
             stages = [[functools.partial(fn, j, r0, r1)
                        for j, (r0, r1) in enumerate(chunks)]
@@ -197,6 +209,7 @@ class HGemms:
                     copy_out=stages[2][0] if has_out else None))
         return tasks
 
+    @functools.partial(jax.profiler.annotate_function, name="poas.execute")
     def execute(self, a: np.ndarray, b: np.ndarray, *,
                 noise: float = 0.0, seed: int = 0,
                 plan: POASPlan | None = None) -> tuple[np.ndarray, ExecutionReport]:
@@ -206,9 +219,11 @@ class HGemms:
         Partitions run concurrently through ``OverlappedExecutor`` on their
         bound devices (real numerics, real overlap, bus order from the
         plan); ``report.measured`` holds the executor's measured stage
-        intervals.  The report's simulated times come from the device models
-        (optionally noised), so an unbound simulated testbed reproduces the
-        paper's timing behaviour deterministically on one CPU.
+        intervals, each with its ``phases`` and its ticket ``wait`` (the
+        call is the profiler span ``poas.execute``).  The report's
+        simulated times come from the device models (optionally noised),
+        so an unbound simulated testbed reproduces the paper's timing
+        behaviour deterministically on one CPU.
         """
         m, k = a.shape
         k2, n = b.shape
@@ -253,7 +268,6 @@ class HGemms:
             simulated_makespan=max(tl.makespan,
                                    max(device_times.values(), default=0.0)),
             wall_seconds=wall, standalone=standalone,
-            per_device_seconds=device_times,
             measured=measured, placement=placement)
         return c, rep
 

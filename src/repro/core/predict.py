@@ -16,9 +16,11 @@ Builds per-device performance models.  Three sources, all producing the same
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Sequence
 
+import jax
 import numpy as np
 
 from .device_model import (CopyModel, DeviceProfile, LinearTimeModel,
@@ -91,6 +93,7 @@ class Profiler:
         self.repeats = repeats
         self.records: list[ProfileRecord] = []
 
+    @functools.partial(jax.profiler.annotate_function, name="poas.predict")
     def run(self, sizes: Sequence[int]) -> list[ProfileRecord]:
         self.records = []
         for s in sizes:
@@ -112,8 +115,6 @@ def device_runner(device, matmul: Callable, dtype) -> Callable[[int], float]:
     of ``dtype`` are committed to ``device`` and warmed once (compile + first
     run) per size; each call then times one run ending in
     ``block_until_ready``."""
-    import jax
-
     held: dict[int, tuple] = {}
 
     def run(size: int) -> float:
@@ -159,13 +160,12 @@ def measure_bandwidth_simulated(profile: DeviceProfile, *, nbytes: int = 1 << 28
     return nbytes / max(t, 1e-12)
 
 
+@functools.partial(jax.profiler.annotate_function, name="poas.predict")
 def measure_bandwidth(device, *, nbytes: int = 256 << 20,
                       repeats: int = 5) -> float:
     """Paper's memory-bandwidth micro-benchmark on a real host link: the
     median over ``repeats`` of a timed host->device ``device_put`` of
     ``nbytes``, after one warm-up transfer."""
-    import jax
-
     host = np.random.default_rng(0).integers(0, 256, nbytes, dtype=np.uint8)
     jax.device_put(host, device).block_until_ready()
     times = []
