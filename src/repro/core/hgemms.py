@@ -16,10 +16,11 @@ place.  Inside the stages, ``phase``s split the work the stage interval
 holds: a copy_out's ``d2h`` read and its ``store`` into C; an in-place
 compute's operand ``put``, its ``kernel`` and its ``store``.  A profile
 with a host link (an accelerator such as a TPU chip) runs the Pallas MXU
-kernel (``repro.kernels.matmul``); a profile without one runs an
-f32-accumulating XLA matmul.  Operands cross the link in the dtype the
-caller hands in — bf16 for a chip, the 2-byte dtype its ``CopyModel``
-prices — and C always accumulates and returns in f32.
+kernel (``repro.kernels.matmul``), its C rows laid out row-major on the
+device so that their store into C is a straight copy; a profile without
+one runs an f32-accumulating XLA matmul.  Operands cross the link in the
+dtype the caller hands in — bf16 for a chip, the 2-byte dtype its
+``CopyModel`` prices — and C always accumulates and returns in f32.
 
 Without ``bind`` every partition runs the XLA matmul on the host CPU device:
 the simulated testbeds (``paper_mach1``/``mach2``) keep real numerics while
@@ -36,6 +37,8 @@ from typing import Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from ..kernels.ops import matmul as pallas_matmul
 from .adapt import GemmPlan
@@ -60,6 +63,30 @@ def mxu_matmul(a: jax.Array, b: jax.Array, *,
     """An accelerator's kernel: the Pallas MXU matmul (``kernels.matmul``,
     jitted at module level) with f32 accumulation and output."""
     return pallas_matmul(a, b, out_dtype=jnp.float32, interpret=interpret)
+
+
+def row_major_matmul(dev: jax.Device, interpret: bool = False) -> Callable:
+    """``mxu_matmul`` as one program pinned to ``dev`` whose C rows come
+    out row-major, the layout of the host C they are stored into.
+
+    Left to itself, the TPU compiler lays an (m, n) result out in whichever
+    order pads its (8, 128) tiles least: column-major when ``m`` pads less
+    than ``n``, as for most partition shares.  The host would then read
+    F-ordered rows and store them into C with a transposing copy.  Built
+    once per device, so a warm ``execute`` traces nothing; the module keeps
+    the kernel's name, ``jit_matmul``."""
+    return _row_major(mxu_matmul, dev, interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_major(kernel: Callable, dev: jax.Device, interpret: bool) -> Callable:
+    # keyed by the kernel too: the program runs what ``mxu_matmul`` names
+    # when it is asked for, as the unpinned call did
+    def matmul(a, b):
+        return kernel(a, b, interpret=interpret)
+
+    return jax.jit(matmul, out_shardings=Format(
+        Layout(major_to_minor=(0, 1)), SingleDeviceSharding(dev)))
 
 
 @dataclasses.dataclass
@@ -125,10 +152,10 @@ class HGemms:
         """The device a profile's partitions run on, and their kernel."""
         if self.bind is None:
             return jax.devices("cpu")[0], host_matmul
+        dev = self.bind[prof.name]
         if math.isinf(prof.copy.bandwidth_bytes_per_s):
-            return self.bind[prof.name], host_matmul
-        return self.bind[prof.name], functools.partial(
-            mxu_matmul, interpret=self.interpret)
+            return dev, host_matmul
+        return dev, row_major_matmul(dev, self.interpret)
 
     def _partition_tasks(self, a: np.ndarray, b: np.ndarray, c: np.ndarray,
                          gplan: GemmPlan, planned: Timeline,
@@ -188,7 +215,8 @@ class HGemms:
                 nbytes = (r1 - r0) * c.shape[1] * c.itemsize
                 with phase("d2h", bytes=nbytes):
                     host = np.asarray(state.pop(("c", j)))
-                with phase("store", bytes=nbytes):
+                with phase("store", bytes=nbytes,
+                           row_major=host.flags.c_contiguous):
                     c[r0:r1] = host
                     del host
 

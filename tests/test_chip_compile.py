@@ -3,13 +3,14 @@ paper's input i1 (m = n = k = 30000): bf16 in, f32 out, Mosaic custom call
 present, program within one chip's HBM.  Nothing runs; the TPU compiler
 refuses here what the chip would refuse."""
 import os
+import re
 
 import jax
 import ml_dtypes
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.hgemms import mxu_matmul
+from repro.core.hgemms import mxu_matmul, row_major_matmul
 
 N = K = 30_000
 V5E_HBM_BYTES = 16 * 2**30
@@ -17,6 +18,9 @@ V5E_HBM_BYTES = 16 * 2**30
 # share of a one-chip plan, and one chip's quarter of a four-chip plan.
 ONE_CHIP_ROWS = 29_856
 FOUR_CHIP_ROWS = 7_464
+# The chip share of a one-chip-plus-CPU plan whose rows pad less than its
+# columns, so the compiler on its own lays C out column-major.
+COLUMN_MAJOR_ROWS = 29_552
 
 
 @pytest.fixture(scope="module")
@@ -60,3 +64,20 @@ def test_mxu_partition_compiles_for_v5e(rows, one_chip, no_persistent_cache):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total <= V5E_HBM_BYTES, total
+
+
+def test_pinned_partition_returns_row_major_c_for_v5e(topo, one_chip,
+                                                      no_persistent_cache):
+    """The chip partition's program as ``HGemms`` runs it: C rows come out
+    row-major with no relayout copy of C, in the module the benchmark's
+    roofline reads (``jit_matmul``)."""
+    bf16 = ml_dtypes.bfloat16
+    rows = COLUMN_MAJOR_ROWS
+    compiled = row_major_matmul(topo.devices[0]).lower(
+        jax.ShapeDtypeStruct((rows, K), bf16, sharding=one_chip),
+        jax.ShapeDtypeStruct((K, N), bf16, sharding=one_chip)).compile()
+    assert compiled.output_formats.layout.major_to_minor == (0, 1)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= f32\[[^\]]*\]\S* copy\(", text)
+    assert re.match(r"HloModule jit_matmul\b", text)

@@ -1,6 +1,7 @@
 """The executor's phases and ticket waits: recorded in each stage's
 ``BusEvent`` on the stream clock, and written to the profiler's trace as
 ``poas.*`` host spans on the device's clock, from one recording path."""
+import functools
 import pathlib
 import threading
 import time
@@ -13,6 +14,7 @@ import pytest
 from repro.core import (NO_COPY, CopyModel, DeviceProfile, DeviceTask, HGemms,
                         LinearTimeModel, StreamCore)
 from repro.core.executor import phase
+from repro.core.hgemms import host_matmul, mxu_matmul, row_major_matmul
 
 
 def _bound(pipeline_chunks=None, m=256, n=384, k=128):
@@ -162,6 +164,42 @@ def test_spans_land_in_the_profiler_trace_as_the_report_times_them(tmp_path):
     rows = [asg.m for asg in rep.plan.adapted.assignments
             if asg.device == "chip"][0]
     assert d2h[3]["bytes"] == rows * b.shape[1] * 4
+
+
+@pytest.mark.parametrize("pipeline_chunks", [None, 2],
+                         ids=["one-chunk", "two-chunks"])
+def test_chip_rows_are_stored_row_major_as_the_unpinned_kernel_computes_them(
+        tmp_path, pipeline_chunks):
+    """A chip share that is not square: C is, bit for bit, what the
+    unpinned chip kernel and the host's kernel give its rows, and every
+    store of the chip's rows into C copies row-major rows."""
+    m, n, k = 200, 384, 256
+    hg, _, _ = _bound(pipeline_chunks=pipeline_chunks, m=m, n=n, k=k)
+    rng = np.random.default_rng(5)
+    a = rng.uniform(-1, 1, (m, k)).astype(ml_dtypes.bfloat16)
+    b = rng.uniform(-1, 1, (k, n)).astype(ml_dtypes.bfloat16)
+    plan = hg.plan(m, n, k)
+    kernels = {"host": host_matmul,
+               "chip": functools.partial(mxu_matmul, interpret=True)}
+    want = np.zeros((m, n), np.float32)
+    for asg in plan.adapted.assignments:
+        assert 0 < asg.m != n
+        for r0, rows in zip(asg.chunk_offsets(), asg.chunk_rows):
+            want[r0:r0 + rows] = np.asarray(
+                kernels[asg.device](a[r0:r0 + rows], b))
+    cpu = jax.devices("cpu")[0]
+    (chip,) = [p for p in hg.devices if p.name == "chip"]
+    assert hg._target(chip)[1] is row_major_matmul(cpu, True)
+    hg.execute(a, b)                      # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        c, rep = hg.execute(a, b)
+    np.testing.assert_array_equal(c, want)
+    stores = [s for s in _host_spans(tmp_path)
+              if s[0] == "poas.copy_out.store"]
+    outs = [e for e in rep.measured.device_events("chip")
+            if e.kind == "copy_out"]
+    assert len(stores) == len(outs) == (pipeline_chunks or 1)
+    assert [s[3]["row_major"] for s in stores] == [True] * len(stores)
 
 
 def test_a_phase_lands_in_the_stage_of_its_own_thread():
