@@ -47,6 +47,7 @@ from .device_model import DeviceProfile, with_pipeline
 from .domain import PlanCache
 from .executor import DeviceTask, OverlappedExecutor, phase
 from .framework import GemmWorkload, POASPlan, make_gemm_poas
+from .predict import Pool, pool_profiles
 from .schedule import DynamicScheduler, Timeline, simulate_timeline
 
 
@@ -100,6 +101,7 @@ class ExecutionReport:
     measured: Timeline | None = None   # executor's real per-stage intervals
     # profile name -> the jax devices its computed C blocks lived on
     placement: dict[str, set] = dataclasses.field(default_factory=dict)
+    pools: tuple[Pool, ...] = ()   # HGemms.pools: the alike devices pooled
 
     @property
     def speedups(self) -> dict[str, float]:
@@ -112,7 +114,13 @@ class HGemms:
 
     ``bind`` maps every profile name to the ``jax.Device`` its partitions
     run on; ``interpret`` runs the accelerators' Pallas kernel in interpret
-    mode (CPU tests bind the CPU device in the chip's place)."""
+    mode (CPU tests bind the CPU device in the chip's place).  Bound
+    profiles of one hardware identity (``_hardware``: the same kind, bound
+    to the same kind of ``jax.Device``, with the same alignment, copy dtype
+    and host link) are pooled into one model (``predict.pool_profiles``),
+    so a plan over alike chips does not follow their fits' noise;
+    ``pools`` holds each group of two or more.  Unbound (simulated)
+    profiles are planned as given."""
 
     def __init__(self, devices: Sequence[DeviceProfile], *,
                  bus: str | BusTopology = "serialized",
@@ -132,6 +140,10 @@ class HGemms:
                                  f"{sorted(names)}, got {sorted(bind)}")
         self.bind = dict(bind) if bind is not None else None
         self.interpret = interpret
+        self.pools: tuple[Pool, ...] = ()
+        if self.bind is not None:
+            self.devices, self.pools = pool_profiles(self.devices,
+                                                     self._hardware)
         self.poas, self.dyn = make_gemm_poas(self.devices, bus=bus,
                                              dynamic=dynamic, cache=cache)
         self.bus = self.poas.domain.bus
@@ -147,6 +159,12 @@ class HGemms:
         return self.poas.plan(GemmWorkload(m=m, n=n, k=k))
 
     # -- execution ---------------------------------------------------------
+
+    def _hardware(self, prof: DeviceProfile) -> tuple:
+        """What makes two bound profiles the same hardware."""
+        return (prof.kind, self.bind[prof.name].device_kind, prof.align_m,
+                prof.align_k, prof.copy.dtype_size,
+                not math.isinf(prof.copy.bandwidth_bytes_per_s))
 
     def _target(self, prof: DeviceProfile) -> tuple[jax.Device, Callable]:
         """The device a profile's partitions run on, and their kernel."""
@@ -296,7 +314,7 @@ class HGemms:
             simulated_makespan=max(tl.makespan,
                                    max(device_times.values(), default=0.0)),
             wall_seconds=wall, standalone=standalone,
-            measured=measured, placement=placement)
+            measured=measured, placement=placement, pools=self.pools)
         return c, rep
 
     # -- prediction accuracy experiment (paper §5.2) ------------------------

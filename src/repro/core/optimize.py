@@ -6,15 +6,17 @@ and solves it with CPLEX.  CPLEX is unavailable here; the problem class is
 small (a handful of devices) and the per-device time models are monotone
 non-decreasing in ``c_x``, so we replace the external solver with:
 
-* ``solve_bisection`` — exact for *any* monotone time model (subsumes the
-  paper's linear MILP): bisect on the makespan T; feasibility is "can the
+* ``solve_bisection`` — bisect on the makespan T; feasibility is "can the
   devices jointly absorb N ops, each finishing by T?", which decomposes
-  per-device on uncontended topologies.  On contended topologies (the
-  paper's serialized shared bus, §3.4.3/Fig. 2) the greedy priority-ordered
-  feasibility check prices every candidate against the *exact* unified
-  timeline engine (``core.bus``) — including chunked pipelined copies — so
-  the solver optimizes precisely what the simulator reports and the
-  executor replays.
+  per-device on uncontended topologies, where the result is exact for
+  *any* monotone time model (subsumes the paper's linear MILP).  On
+  contended topologies (the paper's serialized shared bus, §3.4.3/Fig. 2)
+  the greedy priority-ordered feasibility check prices every candidate
+  against the *exact* unified timeline engine (``core.bus``) — including
+  chunked pipelined copies — so the solver optimizes precisely what the
+  simulator reports and the executor replays; there it is exact only per
+  priority order, and a count search over alike devices
+  (``_count_search``) covers the splits the greedy misses among them.
 * ``solve_analytic`` — closed-form active-set LP for the linear,
   independent-bus case (for cross-checking, and it is what a CPLEX run of
   Eqs. 1–4 returns).
@@ -38,6 +40,7 @@ import threading
 from collections import OrderedDict
 from typing import Mapping, Sequence
 
+import jax
 import numpy as np
 
 from .bus import (BusTopology, ClockState, GraphSimBatch, GraphSimContext,
@@ -57,6 +60,9 @@ class OptimizeResult:
     bus: str                         # "independent" | "serialized" | custom
     iterations: int = 0
     energy_j: float | None = None    # joules, when an Objective was given
+    # (alike devices offered, model makespan) for every count a contended
+    # solve tried, per group of alike devices (``_count_search``)
+    counts: tuple[tuple[int, float], ...] = ()
 
     def shares(self) -> list[float]:
         n = sum(self.ops)
@@ -210,7 +216,7 @@ def _max_ops_serialized(devices: Sequence[DeviceProfile], order: Sequence[int],
 
 
 # ---------------------------------------------------------------------------
-# Exact bisection solver
+# Bisection solver
 # ---------------------------------------------------------------------------
 
 
@@ -224,9 +230,15 @@ def solve_bisection(devices: Sequence[DeviceProfile], N: float, *,
     ``bus`` is a legacy spec string ("independent" | "serialized") or a
     ``BusTopology``.  Feasibility prices every candidate on the exact
     unified timeline engine, so the check is exact for any topology and for
-    chunked pipelined copies; the contended-topology result is additionally
-    *polished* by coordinate descent on the same engine (the greedy
-    priority-ordered assignment is not always the global optimum).
+    chunked pipelined copies.  On an uncontended topology the result is the
+    exact optimum.  On a contended one the greedy assignment is exact only
+    *per priority order*: it fills devices in that order, so the result is
+    polished by coordinate descent on the same engine, and can still get
+    stuck where several devices are alike (the greedy gives the first of
+    them nearly everything).  So, where some devices are equal as values,
+    ``_count_search`` also solves with only the first j of each such group
+    and keeps the best split: offering one more alike device never gives a
+    worse model makespan.  ``OptimizeResult.counts`` records what it tried.
 
     ``objective``: with a pure-makespan objective (None / weight 0) the
     selection is exactly the historical one; an energy-weighted objective
@@ -240,6 +252,47 @@ def solve_bisection(devices: Sequence[DeviceProfile], N: float, *,
         z = [0.0] * len(devices)
         return OptimizeResult(z, 0.0, z, spec)
     topo = BusTopology.from_spec(bus, devices)
+    best = _solve_makespan(devices, N, n, k, topo, spec, tol, polish)
+    if topo.is_contended():
+        best = _count_search(devices, best, N, n, k, topo, spec, tol, polish)
+    if objective is None:
+        return best
+    best.energy_j = divisible_energy(devices, best.ops, best.makespan)
+    if objective.is_makespan or len(devices) <= 1:
+        return best
+    iters = best.iterations
+    # energy mode: re-score against every proper device-subset split —
+    # each subset solved makespan-optimally by the exact machinery above,
+    # then priced with the idle watts of the devices it left out
+    best_score = objective.score(best.makespan, best.energy_j)
+    m = len(devices)
+    for mask in range(1, (1 << m) - 1):
+        idxs = [i for i in range(m) if mask >> i & 1]
+        sub = [devices[i] for i in idxs]
+        r = solve_bisection(sub, N, n=n, k=k,
+                            bus=bus if isinstance(bus, BusTopology)
+                            else spec, tol=tol, polish=polish)
+        ops_full = [0.0] * m
+        for i, c in zip(idxs, r.ops):
+            ops_full[i] = c
+        e = divisible_energy(devices, ops_full, r.makespan)
+        s = objective.score(r.makespan, e)
+        if s < best_score - _EPS:
+            fin_full = [0.0] * m
+            for i, f in zip(idxs, r.finish_times):
+                fin_full[i] = f
+            best = OptimizeResult(ops_full, r.makespan, fin_full, spec,
+                                  iterations=iters + r.iterations,
+                                  energy_j=e)
+            best_score = s
+    return best
+
+
+def _solve_makespan(devices: Sequence[DeviceProfile], N: float, n: int,
+                    k: int, topo: BusTopology, spec: str, tol: float,
+                    polish: bool) -> OptimizeResult:
+    """The bisection on T over every device offered, polished on a
+    contended topology, and never worse than one device alone."""
     order = priority_order(devices)
     contended = topo.is_contended()
 
@@ -294,36 +347,60 @@ def solve_bisection(devices: Sequence[DeviceProfile], N: float, *,
         f1 = _finish_times(devices, one, n, k, topo, order)
         if max(f1) < best.makespan:
             best = OptimizeResult(one, max(f1), f1, spec, iterations=iters)
-    if objective is None:
-        return best
-    best.energy_j = divisible_energy(devices, best.ops, best.makespan)
-    if objective.is_makespan or len(devices) <= 1:
-        return best
-    # energy mode: re-score against every proper device-subset split —
-    # each subset solved makespan-optimally by the exact machinery above,
-    # then priced with the idle watts of the devices it left out
-    best_score = objective.score(best.makespan, best.energy_j)
-    m = len(devices)
-    for mask in range(1, (1 << m) - 1):
-        idxs = [i for i in range(m) if mask >> i & 1]
-        sub = [devices[i] for i in idxs]
-        r = solve_bisection(sub, N, n=n, k=k,
-                            bus=bus if isinstance(bus, BusTopology)
-                            else spec, tol=tol, polish=polish)
-        ops_full = [0.0] * m
-        for i, c in zip(idxs, r.ops):
-            ops_full[i] = c
-        e = divisible_energy(devices, ops_full, r.makespan)
-        s = objective.score(r.makespan, e)
-        if s < best_score - _EPS:
-            fin_full = [0.0] * m
-            for i, f in zip(idxs, r.finish_times):
-                fin_full[i] = f
-            best = OptimizeResult(ops_full, r.makespan, fin_full, spec,
-                                  iterations=iters + r.iterations,
-                                  energy_j=e)
-            best_score = s
     return best
+
+
+def alike_groups(devices: Sequence[DeviceProfile]) -> list[list[int]]:
+    """Indices of the devices that are equal as values but for their name,
+    one ascending list per group of two or more, in the order of each
+    group's first member."""
+    groups: list[list[int]] = []
+    for i, d in enumerate(devices):
+        for g in groups:
+            if dataclasses.replace(devices[g[0]], name=d.name) == d:
+                g.append(i)
+                break
+        else:
+            groups.append([i])
+    return [g for g in groups if len(g) > 1]
+
+
+def _count_search(devices: Sequence[DeviceProfile], best: OptimizeResult,
+                  N: float, n: int, k: int, topo: BusTopology, spec: str,
+                  tol: float, polish: bool) -> OptimizeResult:
+    """How many of each group of alike devices to use, on a contended
+    topology: solve again with only the first j members of one group
+    (every other device present), for j = 1 .. size - 1, and keep the
+    split with the least makespan.  ``best`` (every member) stands unless
+    a prefix beats it by more than ``_EPS``; among prefixes the first, so
+    fewer devices, wins a tie.  Each count tried is a profiler span
+    ``poas.plan.optimize.count`` and an entry of ``counts``."""
+    groups = alike_groups(devices)
+    if not groups:
+        return best
+    order = priority_order(devices)
+    counts: list[tuple[int, float]] = []
+    chosen = best
+    for g in groups:
+        counts.append((len(g), best.makespan))
+        for j in range(1, len(g)):
+            dropped = set(g[j:])
+            idxs = [i for i in range(len(devices)) if i not in dropped]
+            with jax.profiler.TraceAnnotation("poas.plan.optimize.count",
+                                              used=j) as span:
+                r = _solve_makespan([devices[i] for i in idxs], N, n, k,
+                                    topo, spec, tol, polish)
+                ops = [0.0] * len(devices)
+                for i, c in zip(idxs, r.ops):
+                    ops[i] = c
+                finish = _finish_times(devices, ops, n, k, topo, order)
+                span.set_metadata(makespan=max(finish))
+            counts.append((j, max(finish)))
+            if max(finish) < chosen.makespan - _EPS:
+                chosen = OptimizeResult(ops, max(finish), finish, spec,
+                                        iterations=r.iterations)
+    chosen.counts = tuple(counts)
+    return chosen
 
 
 def _descend(devices: Sequence[DeviceProfile], ops0: Sequence[float],

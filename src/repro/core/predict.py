@@ -4,7 +4,8 @@ Builds per-device performance models.  Three sources, all producing the same
 ``TimeModel`` interface (paper §3.1 stresses modularity of the predictor):
 
 1. ``fit_linear`` — least-squares linear regression of measured time over the
-   op count (the paper's approach, §4.1.1).
+   op count (the paper's approach, §4.1.1); ``pool_profiles`` gives alike
+   devices, fitted one by one, one model between them.
 2. ``Profiler`` — the one-off profiling pass (paper §4.1.2): runs squared
    matmuls of growing size, measures, and regresses.  ``device_runner``
    times a kernel on a real ``jax.Device`` (the host CPU, a TPU chip) and
@@ -17,8 +18,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
-from typing import Callable, Sequence
+from typing import Callable, Hashable, NamedTuple, Sequence
 
 import jax
 import numpy as np
@@ -67,6 +69,61 @@ def relative_error(predicted: float, measured: float) -> float:
 def rmse(errors_pct: Sequence[float]) -> float:
     e = np.asarray(errors_pct, dtype=np.float64)
     return float(np.sqrt(np.mean(e ** 2)))
+
+
+class Pool(NamedTuple):
+    """A group of alike devices given one model: the members' names, and
+    the largest ``max / min - 1`` of their fitted seconds per MAC and of
+    their host-link bandwidths, before pooling (the fits' noise)."""
+    members: tuple[str, ...]
+    spread: float
+
+
+def _median_model(models: Sequence):
+    """The models' field-wise median (a field equal in every model is kept
+    as it is)."""
+    fields = {}
+    for f in dataclasses.fields(models[0]):
+        vals = [getattr(m, f.name) for m in models]
+        fields[f.name] = (vals[0] if all(v == vals[0] for v in vals)
+                          else float(np.median(vals)))
+    return type(models[0])(**fields)
+
+
+def pool_profiles(devices: Sequence[DeviceProfile],
+                  identity: Callable[[DeviceProfile], Hashable]
+                  ) -> tuple[list[DeviceProfile], tuple[Pool, ...]]:
+    """Give the devices of one hardware identity one time model and one
+    host-link bandwidth, the members' field-wise medians, so that the
+    solver sees alike devices as equal and not as apart by their fits'
+    noise.  Names, order and every other field stay as given; a device
+    alone in its identity is returned as it is.  Each pooled group of two
+    or more is a ``Pool`` and a profiler span ``poas.predict.pool``."""
+    groups: dict[Hashable, list[int]] = {}
+    for i, d in enumerate(devices):
+        key = (identity(d), type(d.compute), type(d.copy))
+        groups.setdefault(key, []).append(i)
+    out, pools = list(devices), []
+    for idx in groups.values():
+        if len(idx) < 2:
+            continue
+        members = [devices[i] for i in idx]
+        names = tuple(d.name for d in members)
+        bws = [d.copy.bandwidth_bytes_per_s for d in members]
+        s_per_mac = [1.0 / d.effective_speed for d in members]
+        spread = max(max(s_per_mac) / min(s_per_mac),
+                     1.0 if math.isinf(bws[0]) else max(bws) / min(bws)) - 1.0
+        with jax.profiler.TraceAnnotation("poas.predict.pool",
+                                          kind=members[0].kind,
+                                          members=" ".join(names),
+                                          spread=spread):
+            compute = _median_model([d.compute for d in members])
+            copy = _median_model([d.copy for d in members])
+            for i in idx:
+                out[i] = dataclasses.replace(devices[i], compute=compute,
+                                             copy=copy)
+        pools.append(Pool(names, spread))
+    return out, tuple(pools)
 
 
 # ---------------------------------------------------------------------------
