@@ -22,6 +22,12 @@ one runs an f32-accumulating XLA matmul.  Operands cross the link in the
 dtype the caller hands in — bf16 for a chip, the 2-byte dtype its
 ``CopyModel`` prices — and C always accumulates and returns in f32.
 
+A store of rows into C (``_store_rows``) is spread over host threads by its
+size: C is a fresh ``np.zeros``, so the store is the first write to each of
+its pages, and page faults on separate row blocks are taken in parallel
+(numpy releases the GIL for the copy).  A store of less than
+``_STORE_BLOCK_FLOOR`` bytes stays one copy on the calling thread.
+
 Without ``bind`` every partition runs the XLA matmul on the host CPU device:
 the simulated testbeds (``paper_mach1``/``mach2``) keep real numerics while
 their per-device *times* come from the device models.
@@ -31,7 +37,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import os
+import threading
 import time
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Callable, Mapping, Sequence
 
 import jax
@@ -88,6 +97,53 @@ def _row_major(kernel: Callable, dev: jax.Device, interpret: bool) -> Callable:
 
     return jax.jit(matmul, out_shardings=Format(
         Layout(major_to_minor=(0, 1)), SingleDeviceSharding(dev)))
+
+
+# A store of rows into C takes one host thread per ``_STORE_BLOCK_FLOOR``
+# bytes, up to the usable CPUs and ``_STORE_THREADS_CAP``: the smallest
+# thread count whose store of a chip share's rows into a fresh C came within
+# 10% of the fastest (PERF.md §6, PR 18).
+_STORE_BLOCK_FLOOR = 64 << 20
+_STORE_THREADS_CAP = 8
+_store_pool: ThreadPoolExecutor | None = None   # made by the first store
+_store_pool_lock = threading.Lock()
+
+
+def _store_threads(rows: int, row_bytes: int) -> int:
+    """How many host threads a store of ``rows`` rows into C takes."""
+    return max(1, min(_STORE_THREADS_CAP, len(os.sched_getaffinity(0)), rows,
+                      -(-rows * row_bytes // _STORE_BLOCK_FLOOR)))
+
+
+def _store_executor() -> ThreadPoolExecutor:
+    global _store_pool
+    with _store_pool_lock:
+        if _store_pool is None:
+            _store_pool = ThreadPoolExecutor(_STORE_THREADS_CAP,
+                                             thread_name_prefix="poas-store")
+        return _store_pool
+
+
+def _copy_rows(c: np.ndarray, r0: int, rows: np.ndarray) -> None:
+    c[r0:r0 + len(rows)] = rows
+
+
+def _store_rows(c: np.ndarray, r0: int, rows: np.ndarray) -> int:
+    """``c[r0:r0 + len(rows)] = rows``, as contiguous row blocks copied at
+    once by the module's store pool (``_store_threads``).  Waits for every
+    block and raises the first block's error; returns the threads used."""
+    threads = _store_threads(len(rows), c.shape[1] * c.itemsize)
+    if threads == 1:
+        _copy_rows(c, r0, rows)
+        return 1
+    bounds = [len(rows) * i // threads for i in range(threads + 1)]
+    pool = _store_executor()
+    futures = [pool.submit(_copy_rows, c, r0 + i0, rows[i0:i1])
+               for i0, i1 in zip(bounds, bounds[1:])]
+    wait(futures)
+    for f in futures:
+        f.result()
+    return threads
 
 
 @dataclasses.dataclass
@@ -185,6 +241,7 @@ class HGemms:
         copy, the overlap the chunked plan prices.  The shared B panel
         rides input chunk 0, exactly how the engine prices it."""
         planned_kinds = {(e.device, e.kind) for e in planned.events}
+        row_bytes = c.shape[1] * c.itemsize
         tasks: list[DeviceTask] = []
         for prof, asg in zip(self.devices, gplan.assignments):
             if asg.m == 0:
@@ -224,18 +281,19 @@ class HGemms:
                 if has_out:
                     state["c", j] = out
                 else:
-                    with phase("store",
-                               bytes=(r1 - r0) * c.shape[1] * c.itemsize):
-                        c[r0:r1] = np.asarray(out)
+                    with phase("store", bytes=(r1 - r0) * row_bytes,
+                               threads=_store_threads(r1 - r0, row_bytes)):
+                        _store_rows(c, r0, np.asarray(out))
 
             def copy_out(j, r0, r1, state=state):
                 # device -> host; dropping the array frees its device memory
-                nbytes = (r1 - r0) * c.shape[1] * c.itemsize
+                nbytes = (r1 - r0) * row_bytes
                 with phase("d2h", bytes=nbytes):
                     host = np.asarray(state.pop(("c", j)))
                 with phase("store", bytes=nbytes,
-                           row_major=host.flags.c_contiguous):
-                    c[r0:r1] = host
+                           row_major=host.flags.c_contiguous,
+                           threads=_store_threads(r1 - r0, row_bytes)):
+                    _store_rows(c, r0, host)
                     del host
 
             stages = [[functools.partial(fn, j, r0, r1)
